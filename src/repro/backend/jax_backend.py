@@ -14,12 +14,13 @@ dispatch budget"):
   FMA that skips the product's rounding. No in-jit barrier stops it
   (``optimization_barrier``, bitcast round-trips and dual-use tricks
   all fail), so float32 multiply→add seams are fenced with
-  :func:`_round24` — the product is computed *exactly* in float64
-  (24-bit × 24-bit mantissas fit 53 bits) and rounded back to float32
-  by integer bit arithmetic XLA cannot fold — and float64 seams keep a
-  kernel boundary (``_probe_parts_j`` / ``_probe_sum_j``).
+  :func:`repro.kernels.counter_hash.round24` — the product is computed
+  *exactly* in float64 (24-bit × 24-bit mantissas fit 53 bits) and
+  rounded back to float32 by integer bit arithmetic XLA cannot fold —
+  and float64 seams keep a kernel boundary (``_probe_parts_j`` /
+  ``_probe_sum_j``).
 * **reassociation** — back-to-back multiplies ``(x·c1)·c2`` fuse into
-  one rounding; ``_round24`` fences these identically.
+  one rounding; ``round24`` fences these identically.
 
 Float *reductions* whose bits feed scheduling (``np.cumsum`` feeding
 admission takes) are reproduced bit-exactly with a **sequential
@@ -35,7 +36,7 @@ axis (declared as an abstract ``("domains",)`` mesh via
 Two mechanical points keep jit practical on this workload:
 
 * **x64** — the scheduler mixes uint64 hashes and float64 scores, so
-  every device call runs under ``jax.experimental.enable_x64`` (scoped:
+  every device call runs under ``jax.enable_x64(True)`` (scoped:
   the training stack's float32 default is untouched);
 * **shape bucketing** — candidate counts vary per round and per chunk,
   and XLA retraces per shape, so inputs are padded to power-of-two row
@@ -57,6 +58,13 @@ the probe's ``top_m`` runs), and 1 per admission chunk pass
 Small chunks stay on the inherited host reference (identical bits,
 lower latency than a device dispatch); ``_DEVICE_MIN_ROWS`` is the
 crossover.
+
+Platforms: XLA:TPU refuses the ``round24`` fence (its float64 → uint64
+``bitcast_convert_type`` cannot be rewritten without X64 types), so
+``synth_window`` and ``forecast_noise_z`` do not compile for a TPU.
+Constructing this backend on a TPU raises instead of routing those ops
+to the host; the scheduler runs on the NumPy reference there until a
+32-bit-limb lowering lands (ROADMAP S4).
 """
 from __future__ import annotations
 
@@ -66,7 +74,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+
+from repro.kernels.counter_hash import round24
 
 from .base import MARGIN
 from .numpy_backend import NumpyBackend
@@ -116,38 +125,7 @@ def _pad_rows(a: np.ndarray, n_pad: int, fill=0):
 
 
 # --------------------------------------------------------------------------
-# in-jit rounding fence + bit-exact column scan (traced helpers)
-
-
-def _round24(p):
-    """float64 → float32 round-to-nearest-even by integer bit arithmetic.
-
-    The fence for float32 multiply→add and multiply→multiply seams
-    inside one executable: compute the product exactly in float64 (two
-    24-bit mantissas always fit the 53-bit mantissa), then perform the
-    float32 rounding *manually* on the bit pattern. XLA cannot contract
-    through it — the rounding is real integer arithmetic, not a
-    ``convert`` it may elide — so the result is bit-identical to
-    NumPy's independently-rounded float32 op chain. Inputs are products
-    of finite normal float32 values (plus exact zeros), so subnormal /
-    overflow handling is unnecessary; ``p == 0`` keeps its sign.
-    """
-    U = jnp.uint64
-    u = jax.lax.bitcast_convert_type(p, jnp.uint64)
-    sign = (u >> U(63)).astype(jnp.uint32) << jnp.uint32(31)
-    exp = ((u >> U(52)) & U(0x7FF)).astype(jnp.int64) - 1023
-    mant = u & U((1 << 52) - 1)
-    keep = (mant >> U(29)).astype(jnp.int64)
-    rest = mant & U((1 << 29) - 1)
-    half = 1 << 28
-    up = (rest > half) | ((rest == half) & ((keep & 1) == 1))
-    keep = keep + up.astype(jnp.int64)
-    ovf = keep >> 23
-    keep = jnp.where(ovf == 1, 0, keep)
-    exp32 = (exp + ovf + 127).astype(jnp.uint32) << jnp.uint32(23)
-    bits = sign | exp32 | keep.astype(jnp.uint32)
-    out = jax.lax.bitcast_convert_type(bits.astype(jnp.uint32), jnp.float32)
-    return jnp.where(p == 0.0, jnp.float32(0.0) * p.astype(jnp.float32), out)
+# bit-exact column scan (traced helper)
 
 
 def _cumsum_cols(x):
@@ -210,7 +188,7 @@ def _cell_noise_j(fold, rows, t_grid):
 
 # fused synthesis window: level gather + cheap mixer + centered noise +
 # clip in ONE dispatch. The f32 (u−½)·amp product feeding the add is
-# _round24-fenced against FMA contraction (the old two-kernel split at
+# round24-fenced against FMA contraction (the old two-kernel split at
 # this seam is gone)
 @jax.jit
 def _synth_window_j(levels, slot, fold, rows, t0, amp):
@@ -218,22 +196,22 @@ def _synth_window_j(levels, slot, fold, rows, t0, amp):
     t_grid = (t0 + jnp.arange(slot.shape[1], dtype=jnp.int64)).astype(
         jnp.uint64)
     u = _mix_cheap(_cell_key(rows, t_grid) ^ fold)
-    noise = _round24((u - np.float32(0.5)).astype(jnp.float64)
-                     * amp.astype(jnp.float64))
+    noise = round24((u - np.float32(0.5)).astype(jnp.float64)
+                    * amp.astype(jnp.float64))
     return jnp.clip(util + noise, 0.0, 1.0)
 
 
 # fused forecast exponent: splitmix row premix + cheap mixer + the two
-# f32 scale multiplies in ONE dispatch, each multiply _round24-fenced
+# f32 scale multiplies in ONE dispatch, each multiply round24-fenced
 # against reassociation (the old split before ``* std`` is gone)
 @jax.jit
 def _forecast_z_j(fold, rows, now, leads, std):
     row_h = _sm64_j(rows ^ fold)[:, None]
     key = row_h ^ ((now << _U64(20)) + leads[None, :])
     u = _mix_cheap(key ^ fold)
-    t = _round24((u - np.float32(0.5)).astype(jnp.float64)
-                 * np.float64(np.float32(np.sqrt(12.0))))
-    return _round24(t.astype(jnp.float64) * std[None, :].astype(jnp.float64))
+    t = round24((u - np.float32(0.5)).astype(jnp.float64)
+                * np.float64(np.float32(np.sqrt(12.0))))
+    return round24(t.astype(jnp.float64) * std[None, :].astype(jnp.float64))
 
 
 @jax.jit
@@ -332,8 +310,17 @@ def _admit_j(spare, budgets, dom_sel, delta, m_min, m_max, doms):
 
 class JaxBackend(NumpyBackend):
     name = "jax"
+    # what the v5e compiler refuses on this backend's path
+    _TPU_REFUSED = ("XLA:TPU refuses the round24 fence in synth_window / "
+                    "forecast_noise_z (bitcast_convert_type f64 -> u64 "
+                    "needs X64 element types)")
 
     def __init__(self):
+        if _platform() == "tpu":
+            raise RuntimeError(
+                f"backend {self.name!r} does not compile for a TPU: "
+                f"{self._TPU_REFUSED}. Use backend='numpy' on a TPU; the "
+                "32-bit-limb lowering is ROADMAP S4.")
         # the vmapped margin scan batches over this abstract axis; with
         # >1 device the axis maps onto real hardware, on one device it
         # lowers to a single batched scan
@@ -348,7 +335,7 @@ class JaxBackend(NumpyBackend):
         flat = x.ravel()
         n = flat.size
         self._tick(name)
-        with enable_x64():
+        with jax.enable_x64(True):
             out = fn(jnp.asarray(_pad_rows(flat, _bucket(n))), *extra)
             out = np.asarray(out)[:n].astype(dtype, copy=False)
         return out.reshape(x.shape)
@@ -364,7 +351,7 @@ class JaxBackend(NumpyBackend):
         flat = key.ravel()
         n = flat.size
         self._tick("cheap_u01")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _cheap_u01_j(_U64(fold),
                                jnp.asarray(_pad_rows(flat, _bucket(n))))
             out = np.asarray(out)[:n]
@@ -383,7 +370,7 @@ class JaxBackend(NumpyBackend):
             kb = np.ascontiguousarray(np.broadcast_to(k, shape))
             n = h.size
             self._tick("hash64")
-            with enable_x64():
+            with jax.enable_x64(True):
                 out = _chain_j(jnp.asarray(_pad_rows(h.ravel(), _bucket(n))),
                                jnp.asarray(_pad_rows(kb.ravel(), _bucket(n))))
                 h = np.asarray(out)[:n].reshape(shape)
@@ -397,7 +384,7 @@ class JaxBackend(NumpyBackend):
             return super().cell_noise(fold, rows, t_grid)
         rp = _bucket(rows.size)
         self._tick("cell_noise")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _cell_noise_j(_U64(fold),
                                 jnp.asarray(_pad_rows(rows, rp)),
                                 jnp.asarray(t_grid))
@@ -413,7 +400,7 @@ class JaxBackend(NumpyBackend):
         slot_p[:R, :W] = slot
         rows_p = _pad_rows(np.asarray(rows, dtype=np.uint64), rp)
         self._tick("synth_window")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _synth_window_j(jnp.asarray(levels), jnp.asarray(slot_p),
                                   _U64(fold), jnp.asarray(rows_p),
                                   np.int64(t0), np.float32(amp))
@@ -429,7 +416,7 @@ class JaxBackend(NumpyBackend):
         std_b[:horizon] = np.broadcast_to(
             np.asarray(std, dtype=np.float32), (horizon,))
         self._tick("forecast_noise_z")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _forecast_z_j(_U64(fc_fold),
                                 jnp.asarray(_pad_rows(rows, rp)),
                                 _U64(now), jnp.asarray(leads),
@@ -445,7 +432,7 @@ class JaxBackend(NumpyBackend):
         B = spare.shape[0]
         bp = _bucket(B)
         self._tick("take_matrix")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _take_matrix_j(
                 jnp.asarray(_pad_rows(np.ascontiguousarray(spare), bp)),
                 jnp.asarray(_pad_rows(np.ascontiguousarray(budget_rows), bp)),
@@ -458,7 +445,7 @@ class JaxBackend(NumpyBackend):
         B, W = spare.shape
         bp = _bucket(B)
         self._tick("take_reach")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _take_reach_j(
                 jnp.asarray(_pad_rows(np.ascontiguousarray(spare), bp)),
                 jnp.asarray(_pad_rows(np.ascontiguousarray(budget_rows), bp)),
@@ -472,7 +459,7 @@ class JaxBackend(NumpyBackend):
         B = sigma.shape[0]
         bp = _bucket(B)
         self._tick("greedy_scores")
-        with enable_x64():
+        with jax.enable_x64(True):
             score, feas = _greedy_scores_j(
                 jnp.asarray(_pad_rows(sigma, bp)),
                 jnp.asarray(_pad_rows(reach, bp)),
@@ -488,7 +475,7 @@ class JaxBackend(NumpyBackend):
         kp = _bucket(n)
         fills = {"delta": 1.0, "m_min": np.inf}
         self._tick("fleet_cols")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = {k: jnp.asarray(_pad_rows(
                 np.ascontiguousarray(v), kp, fill=fills.get(k, 0)))
                 for k, v in cols.items()}
@@ -497,7 +484,7 @@ class JaxBackend(NumpyBackend):
 
     def score_ub(self, cols, excess_col, dd):
         self._tick("score_ub")
-        with enable_x64():
+        with jax.enable_x64(True):
             ub, n_viable = _score_ub_j(
                 cols["spare_ub"], cols["delta"], cols["m_min"],
                 cols["m_max"], cols["sigma"], cols["dom"],
@@ -510,7 +497,7 @@ class JaxBackend(NumpyBackend):
             # position-descending tie rule, so bits match either route
             return super().top_m(np.asarray(ub), int(M))
         self._tick("top_m")
-        with enable_x64():
+        with jax.enable_x64(True):
             idx, bound = _top_m_j(ub, int(M))
         return np.asarray(idx, dtype=np.int64), float(bound)
 
@@ -519,7 +506,7 @@ class JaxBackend(NumpyBackend):
         if ub.size < _DEVICE_MIN_ROWS or _host_route("adopt_scores"):
             return super().adopt_scores(ub)
         self._tick("adopt_scores")
-        with enable_x64():
+        with jax.enable_x64(True):
             return jnp.asarray(_pad_rows(ub, _bucket(ub.size),
                                          fill=-np.inf))
 
@@ -539,7 +526,7 @@ class JaxBackend(NumpyBackend):
         npad = _bucket(n)
         H = tables["cnt"].shape[1] - 1
         self._tick("segment_reach", 2)
-        with enable_x64():
+        with jax.enable_x64(True):
             di, ji, ai, bi = (jnp.asarray(_pad_rows(x, npad))
                               for x in (dom, j, a, b))
             wj = jnp.asarray(_pad_rows(w, npad))
@@ -555,7 +542,7 @@ class JaxBackend(NumpyBackend):
         n = state["seg"]["a"].size
         if n >= _DEVICE_MIN_ROWS:
             npad = _bucket(n)
-            with enable_x64():
+            with jax.enable_x64(True):
                 state["_dev"] = {
                     "cnt": jnp.asarray(state["tables"]["cnt"]),
                     "csum": jnp.asarray(state["tables"]["csum"]),
@@ -572,7 +559,7 @@ class JaxBackend(NumpyBackend):
         if n >= _DEVICE_MIN_ROWS:
             npad = _bucket(n)
             old = state.get("_dev")
-            with enable_x64():
+            with jax.enable_x64(True):
                 dev = {
                     "dom": jnp.asarray(_pad_rows(new["seg"]["dom"], npad)),
                     "a": jnp.asarray(_pad_rows(new["seg"]["a"], npad)),
@@ -600,7 +587,7 @@ class JaxBackend(NumpyBackend):
         w, _a, _b, j = self.probe_segment_w(state, dd)
         n = w.size
         self._tick("probe_scores", 2)
-        with enable_x64():
+        with jax.enable_x64(True):
             wj = jnp.asarray(_pad_rows(w, dev["npad"]))
             ji = jnp.asarray(_pad_rows(j, dev["npad"]))
             pa, pb = _probe_parts_j(dev["cnt"], dev["dom"], dev["a"],
@@ -619,7 +606,7 @@ class JaxBackend(NumpyBackend):
         bp = _bucket(B)
         doms = np.arange(budgets.shape[0], dtype=np.int64)
         self._tick("margin_prefix_ok")
-        with enable_x64():
+        with jax.enable_x64(True):
             ok = _margin_j(
                 jnp.asarray(_pad_rows(np.ascontiguousarray(drain), bp)),
                 jnp.asarray(_pad_rows(
@@ -639,7 +626,7 @@ class JaxBackend(NumpyBackend):
         bu[:, :W] = budgets
         doms = np.arange(budgets.shape[0], dtype=np.int64)
         self._tick("admit_domains")
-        with enable_x64():
+        with jax.enable_x64(True):
             feas, ok, capped = _admit_j(
                 jnp.asarray(sp), jnp.asarray(bu),
                 jnp.asarray(_pad_rows(

@@ -7,17 +7,17 @@ rows × steps per window, everything else (probes, admissions, reach
 state) inherited from the fused-jit path. Same bit-exactness contract,
 same dispatch budget: one tick per window.
 
-The kernels mix uint64 and so run in interpreter mode off-TPU (see the
-kernel module docstring); on this repo's CPU deployment that is the only
-mode, which makes ``backend="pallas"`` primarily a *correctness anchor*
-for a future 32-bit-limb TPU lowering rather than a speedup over
-``backend="jax"`` today.
+The kernels mix uint64 and run in interpreter mode off the TPU (see the
+kernel module docstring). The v5e compiler refuses them, so this backend,
+like ``backend="jax"``, raises when constructed on a TPU: it is a
+*correctness anchor* for a future 32-bit-limb TPU lowering (ROADMAP S4)
+rather than a speedup over ``backend="jax"`` today.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from jax.experimental import enable_x64
+import jax
 
 from .jax_backend import (_DEVICE_MIN_ROWS, _U64, JaxBackend, _bucket,
                           _pad_rows)
@@ -25,6 +25,9 @@ from .jax_backend import (_DEVICE_MIN_ROWS, _U64, JaxBackend, _bucket,
 
 class PallasBackend(JaxBackend):
     name = "pallas"
+    _TPU_REFUSED = ("Mosaic cannot lower the uint64 lanes of the "
+                    "counter-hash kernels piece_window / forecast_z, and "
+                    "XLA:TPU refuses the inherited round24 fence")
 
     def synth_window(self, levels, slot, fold, rows, t0, amp):
         from ..kernels import ops
@@ -37,7 +40,7 @@ class PallasBackend(JaxBackend):
         slot_p[:R, :W] = slot
         rows_p = _pad_rows(np.asarray(rows, dtype=np.uint64), rp)
         self._tick("synth_window")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = ops.piece_window(levels_p, slot_p, _U64(fold), rows_p,
                                    np.int64(t0), np.float32(amp))
             return np.asarray(out)[:R, :W]
@@ -52,7 +55,7 @@ class PallasBackend(JaxBackend):
         std_b[:horizon] = np.broadcast_to(
             np.asarray(std, dtype=np.float32), (horizon,))
         self._tick("forecast_noise_z")
-        with enable_x64():
+        with jax.enable_x64(True):
             out = ops.forecast_z(_U64(fc_fold), _pad_rows(rows, rp),
                                  _U64(now), std_b)
             # explicit copy: callers apply np.exp(z, out=z) in place

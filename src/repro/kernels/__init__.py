@@ -4,41 +4,19 @@ FedZero itself is a scheduling contribution (no kernel in the paper), but
 the client training workloads it schedules have three hot loops that we
 implement TPU-native: flash attention (+sliding window), the MoE grouped
 GEMM, and the RWKV6 chunked scan. Each has a pure-jnp oracle in ref.py and
-is validated in interpret mode over shape/dtype sweeps. The scheduler
-side contributes the counter-hash synthesis kernels
-(:mod:`.counter_hash`: piece-grid window + forecast exponent), validated
-in interpret mode against the NumPy counter-hash reference bit-for-bit
-and selected via ``backend="pallas"`` in the backend registry.
+is validated in interpret mode over shape/dtype sweeps, and compiled for
+a described v5e by tests/test_v5e_compile.py. The scheduler side
+contributes the counter-hash synthesis kernels (:mod:`.counter_hash`:
+piece-grid window + forecast exponent), validated in interpret mode
+against the NumPy counter-hash reference bit-for-bit and selected via
+``backend="pallas"`` in the backend registry.
 
-jax-version compat policy: Pallas renamed ``pltpu.TPUCompilerParams`` to
-``pltpu.CompilerParams`` across jax releases. Kernels must not reference
-either name directly — they go through :func:`compiler_params`, which
-resolves whichever class the installed jax provides. New version-dependent
-Pallas surface should get the same treatment: one ``getattr``-probing
-helper here, call sites stay version-agnostic.
+No kernel module imports :mod:`repro.backend`; the dependency runs the
+other way.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def compiler_params(**kwargs):
-    """Build TPU compiler params on any supported jax version.
-
-    Resolves ``pltpu.CompilerParams`` (new name) or
-    ``pltpu.TPUCompilerParams`` (jax <= 0.4.x) and instantiates it with
-    ``kwargs`` (e.g. ``dimension_semantics=...``).
-    """
-    cls = getattr(_pltpu, "CompilerParams", None) or getattr(
-        _pltpu, "TPUCompilerParams", None)
-    if cls is None:  # pragma: no cover - very old/unknown jax
-        raise AttributeError(
-            "jax.experimental.pallas.tpu provides neither CompilerParams "
-            "nor TPUCompilerParams")
-    return cls(**kwargs)
-
-
 from . import ops, ref
 from .ops import (flash_attention, forecast_z, moe_gemm, piece_window,
                   rwkv_scan)
 
-__all__ = ["compiler_params", "ops", "ref", "flash_attention", "moe_gemm",
-           "rwkv_scan", "piece_window", "forecast_z"]
+__all__ = ["ops", "ref", "flash_attention", "moe_gemm", "rwkv_scan",
+           "piece_window", "forecast_z"]

@@ -12,18 +12,18 @@ Bit-exactness contract: output must equal the NumPy counter-hash
 reference (:meth:`repro.backend.base.ArrayBackend.synth_window` /
 ``forecast_noise_z``) bit-for-bit. The float32 multiply seams
 (``(u−½)·amp``, ``t·std``) are fenced against FMA contraction and
-reassociation with the same :func:`~repro.backend.jax_backend._round24`
-integer rounding fence the fused jit backend uses — the fence is real
-integer arithmetic inside the kernel body, so it survives whatever the
-surrounding compiler does (docs/backends.md, "fused ops & dispatch
-budget").
+reassociation with :func:`round24`, the integer rounding fence that the
+fused jit backend (:mod:`repro.backend.jax_backend`) imports from here —
+the fence is real integer arithmetic inside the kernel body, so it
+survives whatever the surrounding compiler does (docs/backends.md, "fused
+ops & dispatch budget").
 
-Execution modes: the mixing chain is uint64 arithmetic, which TPU
-vector lanes do not provide natively — these kernels run in interpreter
-mode (CPU CI, and the CPU deployment this repo benchmarks) and are the
-anchor for a future 32-bit-limb TPU lowering; wrappers in
-:mod:`repro.kernels.ops` default ``interpret`` accordingly. They must be
-called under ``jax.experimental.enable_x64`` (uint64 keys, float64
+Execution modes: the mixing chain and the fence are uint64/float64
+arithmetic. The wrappers in :mod:`repro.kernels.ops` interpret them off
+the TPU, like every other kernel. The v5e compiler refuses them (uint64
+lanes fail Mosaic lowering), so the scheduler backends that use them do
+not run on a TPU; a 32-bit-limb lowering is the open route (ROADMAP S4).
+They must be called under ``jax.enable_x64(True)`` (uint64 keys, float64
 rounding fence) — the pallas backend does this; tests use the same
 scope.
 """
@@ -36,14 +36,40 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from . import compiler_params
-# the shared FMA/reassociation rounding fence (see backend docstring);
-# kernels → backend.jax_backend is acyclic (the pallas backend imports
-# this module lazily at registry-resolution time)
-from ..backend.jax_backend import _round24
+from jax.experimental.pallas import tpu as pltpu
 
 _U64 = np.uint64
+
+
+def round24(p):
+    """float64 → float32 round-to-nearest-even by integer bit arithmetic.
+
+    The fence for float32 multiply→add and multiply→multiply seams
+    inside one executable: compute the product exactly in float64 (two
+    24-bit mantissas always fit the 53-bit mantissa), then perform the
+    float32 rounding *manually* on the bit pattern. XLA cannot contract
+    through it — the rounding is real integer arithmetic, not a
+    ``convert`` it may elide — so the result is bit-identical to
+    NumPy's independently-rounded float32 op chain. Inputs are products
+    of finite normal float32 values (plus exact zeros), so subnormal /
+    overflow handling is unnecessary; ``p == 0`` keeps its sign.
+    """
+    U = jnp.uint64
+    u = jax.lax.bitcast_convert_type(p, jnp.uint64)
+    sign = (u >> U(63)).astype(jnp.uint32) << jnp.uint32(31)
+    exp = ((u >> U(52)) & U(0x7FF)).astype(jnp.int64) - 1023
+    mant = u & U((1 << 52) - 1)
+    keep = (mant >> U(29)).astype(jnp.int64)
+    rest = mant & U((1 << 29) - 1)
+    half = 1 << 28
+    up = (rest > half) | ((rest == half) & ((keep & 1) == 1))
+    keep = keep + up.astype(jnp.int64)
+    ovf = keep >> 23
+    keep = jnp.where(ovf == 1, 0, keep)
+    exp32 = (exp + ovf + 127).astype(jnp.uint32) << jnp.uint32(23)
+    bits = sign | exp32 | keep.astype(jnp.uint32)
+    out = jax.lax.bitcast_convert_type(bits.astype(jnp.uint32), jnp.float32)
+    return jnp.where(p == 0.0, jnp.float32(0.0) * p.astype(jnp.float32), out)
 
 
 def _sm64(x):
@@ -73,8 +99,8 @@ def _piece_window_kernel(fold_ref, t0_ref, amp_ref, levels_ref, slot_ref,
          ).astype(jnp.uint64)
     key = (rows_ref[...] << _U64(24)) ^ t
     u = _mix_cheap(key ^ fold_ref[0, 0])
-    noise = _round24((u - np.float32(0.5)).astype(jnp.float64)
-                     * amp_ref[0, 0].astype(jnp.float64))
+    noise = round24((u - np.float32(0.5)).astype(jnp.float64)
+                    * amp_ref[0, 0].astype(jnp.float64))
     o_ref[...] = jnp.clip(util + noise, 0.0, 1.0)
 
 
@@ -89,10 +115,10 @@ def _forecast_z_kernel(fold_ref, now_ref, rows_ref, std_ref, o_ref, *,
              + jax.lax.broadcasted_iota(jnp.uint64, (1, block_w), 1))
     key = row_h ^ ((now_ref[0, 0] << _U64(20)) + leads)
     u = _mix_cheap(key ^ fold)
-    t = _round24((u - np.float32(0.5)).astype(jnp.float64)
-                 * np.float64(np.float32(np.sqrt(12.0))))
-    o_ref[...] = _round24(t.astype(jnp.float64)
-                          * std_ref[...].astype(jnp.float64))
+    t = round24((u - np.float32(0.5)).astype(jnp.float64)
+                * np.float64(np.float32(np.sqrt(12.0))))
+    o_ref[...] = round24(t.astype(jnp.float64)
+                         * std_ref[...].astype(jnp.float64))
 
 
 def _scalar(v, dtype):
@@ -126,7 +152,7 @@ def piece_window(levels, slot, fold, rows, t0, amp, *, block_r: int = 256,
         ],
         out_specs=pl.BlockSpec((br, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, W), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(_scalar(fold, jnp.uint64), _scalar(t0, jnp.int64),
@@ -159,7 +185,7 @@ def forecast_z(fold, rows, now, std, *, block_r: int = 256,
         ],
         out_specs=pl.BlockSpec((br, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, W), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(_scalar(fold, jnp.uint64), _scalar(now, jnp.uint64),

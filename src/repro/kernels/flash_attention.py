@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compiler_params
 
 NEG_INF = -1e30
 
@@ -114,7 +113,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),      # running max
             pltpu.VMEM((bq,), jnp.float32),      # running denom
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True when no TPU is present so the kernels are
-executable (and testable) on CPU; on a real TPU backend they compile to
-Mosaic.
+``interpret`` defaults to :func:`_default_interpret`: True only off the
+TPU, so the kernels run (and are tested) on CPU, and on a TPU every
+kernel compiles to Mosaic. Nothing here interprets because it runs on a
+TPU; a kernel the TPU compiler refuses fails there.
 """
 from __future__ import annotations
 
@@ -20,12 +21,6 @@ from .rwkv_scan import rwkv_scan as _rwkv_scan
 
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _hash_interpret(flag):
-    """The counter-hash kernels mix uint64, which has no native TPU
-    lowering yet — they always interpret unless explicitly forced."""
-    return True if flag is None else flag
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -54,19 +49,21 @@ def rwkv_scan(r, k, v, w, u, chunk: int = 32, interpret: bool | None = None):
 
 
 # the counter-hash synthesis kernels trace uint64/float64 — call under
-# jax.experimental.enable_x64 (the pallas backend and the parity tests do)
+# jax.enable_x64(True) (the pallas backend and the parity tests do)
 @functools.partial(jax.jit, static_argnames=("block_r", "block_w",
                                              "interpret"))
 def piece_window(levels, slot, fold, rows, t0, amp, block_r: int = 256,
                  block_w: int = 256, interpret: bool | None = None):
+    interpret = _default_interpret() if interpret is None else interpret
     return _piece_window(levels, slot, fold, rows, t0, amp,
                          block_r=block_r, block_w=block_w,
-                         interpret=_hash_interpret(interpret))
+                         interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "block_w",
                                              "interpret"))
 def forecast_z(fold, rows, now, std, block_r: int = 256,
                block_w: int = 256, interpret: bool | None = None):
+    interpret = _default_interpret() if interpret is None else interpret
     return _forecast_z(fold, rows, now, std, block_r=block_r,
-                       block_w=block_w, interpret=_hash_interpret(interpret))
+                       block_w=block_w, interpret=interpret)

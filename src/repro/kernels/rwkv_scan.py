@@ -15,7 +15,9 @@ inclusive per-channel cumulative decay a_t = Π_{i≤t} w_i:
     S_out = diag(a_T) S_in + ((a_T / a) ⊙ k)^T @ v
 
 Everything inside a chunk is three (T×dh)·(dh×dh/T) matmuls + a masked
-(T×T) correction — MXU food. The state S (dh×dh fp32) lives in VMEM
+(T×T) correction — MXU food. a_t is exp of the prefix sum of log w, and
+that prefix sum is itself a (T×T)·(T×dh) matmul with a lower-triangular
+ones matrix: Mosaic has no cumulative-product lowering. The state S (dh×dh fp32) lives in VMEM
 scratch and is carried across the sequential chunk grid axis. The k/a
 rescaling is numerically safe for chunk sizes ≤64 because w ∈ (0,1) and
 fp32 headroom covers 64 steps of the steepest decay used by RWKV6.
@@ -31,8 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compiler_params
-
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *, T, dh):
     ci = pl.program_id(1)
@@ -47,16 +47,20 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *, T, dh):
     w = w_ref[0].astype(jnp.float32)      # decay in (0, 1)
     u = u_ref[0].astype(jnp.float32)      # [1, dh] bonus
 
-    a = jnp.cumprod(w, axis=0)            # inclusive decay a_t
-    a_prev = a / w                        # a_{t-1} (a_0 / w_0 = 1)
-    S_in = state_ref[...]                 # [dh, dh]
-
-    rq = r * a_prev                       # decay-adjusted queries
-    ks = k / a                            # decay-adjusted keys
-    # intra-chunk pairwise scores, strictly causal (j < t)
-    scores = jax.lax.dot_general(rq, ks, (((1,), (1,)), ((), ())))  # [T, T]
+    # inclusive log-decay prefix la_t = Σ_{i≤t} log w_i as a lower-
+    # triangular matmul (Mosaic has no cumprod lowering); a_t = exp(la_t)
+    logw = jnp.log(w)
     tpos = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
     jpos = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    tril = (jpos <= tpos).astype(jnp.float32)
+    la = jax.lax.dot_general(tril, logw, (((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST)
+    S_in = state_ref[...]                 # [dh, dh]
+
+    rq = r * jnp.exp(la - logw)           # decay-adjusted queries (a_{t-1})
+    ks = k * jnp.exp(-la)                 # decay-adjusted keys (k / a)
+    # intra-chunk pairwise scores, strictly causal (j < t)
+    scores = jax.lax.dot_general(rq, ks, (((1,), (1,)), ((), ())))  # [T, T]
     scores = jnp.where(jpos < tpos, scores, 0.0)
     intra = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())))
     cross = jax.lax.dot_general(rq, S_in, (((1,), (0,)), ((), ())))
@@ -65,9 +69,9 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *, T, dh):
     o_ref[0] = (cross + intra + bonus).astype(o_ref.dtype)
 
     # state update
-    aT = a[-1:, :]                        # [1, dh]
-    k_scaled = (aT / a) * k               # [T, dh]
-    state_ref[...] = aT.T * S_in + jax.lax.dot_general(
+    laT = la[T - 1:T, :]                  # [1, dh]
+    k_scaled = jnp.exp(laT - la) * k      # (a_T / a) ⊙ k, [T, dh]
+    state_ref[...] = jnp.exp(laT).T * S_in + jax.lax.dot_general(
         k_scaled, v, (((0,), (0,)), ((), ())))
 
 
@@ -101,7 +105,7 @@ def rwkv_scan(r, k, v, w, u, *, chunk: int = 32, interpret: bool = False):
         out_specs=pl.BlockSpec((1, T, dh), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rb, kb, vb, wb, ub)

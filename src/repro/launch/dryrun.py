@@ -179,7 +179,7 @@ def _compile_once(cfg, shape_name: str, mesh, strategy: str, unroll: bool,
             _sharded_bytes(specs["cache"], cspec, mesh))
 
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
         out["compile_s"] = round(time.time() - t0, 2)
         if want_memory:

@@ -5,8 +5,10 @@
 
 Runs the real distributed train_step (same code path the dry-run lowers)
 on whatever mesh the current backend offers: the full production mesh on a
-pod, a 1×1 mesh on this CPU container. Synthetic LM data (Zipf tokens with
+pod, a 1×1 mesh on one device. Synthetic LM data (Zipf tokens with
 learnable bigram structure) feeds the loss; checkpoints go to --ckpt-dir.
+chip_smoke.py drives the same mesh and jit (:func:`fit_mesh`,
+:func:`jit_train_step`) on one and on four TPU chips.
 """
 from __future__ import annotations
 
@@ -17,9 +19,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.steps import make_train_step
 from repro.optim import adamw
@@ -38,14 +41,32 @@ def synthetic_lm_batch(rng: np.random.Generator, batch: int, seq: int,
             "labels": jnp.asarray(toks[:, 1:])}
 
 
-def fit_mesh():
-    n = len(jax.devices())
-    model_par = 1
-    for cand in (16, 8, 4, 2, 1):
-        if n % cand == 0 and cand <= n:
-            model_par = cand
-            break
-    return jax.make_mesh((n // model_par, model_par), ("data", "model"))
+def fit_mesh(devices=None):
+    """("data", "model") mesh over ``devices`` (default: all of them), with
+    ``Auto`` axes so the models' activation constraints apply. The model
+    axis takes the largest of 16, 8, 4, 2, 1 that divides the count."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
+    model_par = next(c for c in (16, 8, 4, 2, 1) if n % c == 0)
+    return jax.make_mesh((n // model_par, model_par), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
+
+
+def jit_train_step(train_step, params, opt_state, batch, mesh):
+    """``train_step`` jitted with the repo's parameter, optimizer-state and
+    batch shardings on ``mesh``. Returns ``(jitted, in_shardings)``; the
+    step returns params and optimizer state in their input shardings and
+    a replicated loss, and donates the params and optimizer state it is
+    given. Arguments may be arrays or ``jax.ShapeDtypeStruct`` trees.
+    Call the step inside ``jax.set_mesh(mesh)``."""
+    in_sh = (tree_shardings(param_specs(params, mesh), mesh),
+             tree_shardings(param_specs(opt_state, mesh), mesh),
+             tree_shardings(batch_specs(batch, mesh), mesh))
+    jitted = jax.jit(train_step, in_shardings=in_sh,
+                     out_shardings=(in_sh[0], in_sh[1],
+                                    NamedSharding(mesh, P())),
+                     donate_argnums=(0, 1))
+    return jitted, in_sh
 
 
 def main():
@@ -62,6 +83,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = fit_mesh()
     model, opt, train_step = make_train_step(
@@ -70,11 +92,8 @@ def main():
 
     params = model.init(jax.random.PRNGKey(args.seed))
     opt_state = opt.init(params)
-    pspec = param_specs(params, mesh)
-    ospec = param_specs(opt_state, mesh)
     rng = np.random.default_rng(args.seed)
     batch0 = synthetic_lm_batch(rng, args.batch, args.seq, cfg.vocab)
-    bspec = batch_specs(batch0, mesh)
 
     start = 0
     if args.ckpt_dir and (latest := latest_step(args.ckpt_dir)) is not None:
@@ -83,15 +102,9 @@ def main():
         start = (extra or {}).get("step", latest)
         print(f"resumed from step {start}")
 
-    jitted = jax.jit(train_step,
-                     in_shardings=(tree_shardings(pspec, mesh),
-                                   tree_shardings(ospec, mesh),
-                                   tree_shardings(bspec, mesh)),
-                     out_shardings=(tree_shardings(pspec, mesh),
-                                    tree_shardings(ospec, mesh),
-                                    NamedSharding(mesh, P())))
+    jitted, _ = jit_train_step(train_step, params, opt_state, batch0, mesh)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(start, args.steps):
             batch = synthetic_lm_batch(rng, args.batch, args.seq, cfg.vocab)
             params, opt_state, loss = jitted(params, opt_state, batch)

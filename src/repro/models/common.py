@@ -222,26 +222,16 @@ def head_mask(cfg: ModelConfig):
 # HLO: score matmuls carried all heads per device). Production frameworks pin
 # activation shardings explicitly; ``maybe_shard`` applies a constraint only
 # when an ambient mesh with the named axes is present (so the same model code
-# runs unsharded in tests/CPU training).
+# runs unsharded in tests/CPU training). The ambient mesh is the one set by
+# ``jax.set_mesh``; its axes must be ``Auto`` (launch/train.py builds them
+# so), and a constraint that cannot apply raises.
 
 BATCH_AXES = "__batch__"  # role: ('pod','data') when pod exists, else 'data'
 
 
 def _ambient_mesh():
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def maybe_shard(x, *entries):
@@ -271,10 +261,7 @@ def maybe_shard(x, *entries):
         else:
             spec.append(axes if len(axes) > 1 else axes[0])
     from jax.sharding import PartitionSpec as P
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def cross_entropy_loss(logits, labels, mask=None):

@@ -300,7 +300,13 @@ def _worker_main(conn, cfg, slot: int, plan: Optional[FaultPlan]):
     (counter-seeded synthesis — no trace data crosses the pipe), then
     serve round-shard tasks until told to stop. A plan-scheduled crash
     is a hard ``os._exit`` mid-task: the parent sees the pipe close and
-    drives the retry machinery."""
+    drives the retry machinery.
+
+    Workers run host shards only. They pin JAX to the CPU before anything
+    can initialise a backend, so they never claim the accelerator that
+    the parent process holds."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     from repro.core.experiment import build_registry, build_scenario
     scenario = build_scenario(cfg)
     registry = build_registry(cfg, scenario)

@@ -16,11 +16,6 @@ keeps all 10 heterogeneous architectures lowering with one rule set.
 
 A variant registry (``STRATEGIES``) carries the hillclimb alternatives
 (§Perf): e.g. "tp_only" (no FSDP), "fsdp_only", "2d_ffn".
-
-jax-version compat policy: abstract meshes are built via
-:func:`make_abstract_mesh`, which papers over the ``AbstractMesh``
-constructor change between jax 0.4.x ((name, size) pairs) and newer
-releases ((sizes, names) tuples). Don't call the constructor directly.
 """
 from __future__ import annotations
 
@@ -28,23 +23,20 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (AbstractMesh, AxisType, Mesh, NamedSharding,
+                          PartitionSpec as P)
 
 
 def make_abstract_mesh(axis_sizes: Sequence[int],
                        axis_names: Sequence[str]) -> AbstractMesh:
-    """Build an ``AbstractMesh`` on any supported jax version.
-
-    jax-version compat policy: jax <= 0.4.x constructs ``AbstractMesh``
-    from a tuple of ``(name, size)`` pairs, newer jax from
-    ``(axis_sizes, axis_names)``. Tests and sharding code must go through
-    this helper instead of calling the constructor directly.
-    """
-    assert len(axis_sizes) == len(axis_names)
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """An ``AbstractMesh`` with ``Auto`` axes, the axis type the
+    activation constraints of :func:`repro.models.common.maybe_shard`
+    require."""
+    if len(axis_sizes) != len(axis_names):
+        raise ValueError(f"{len(axis_sizes)} axis sizes for "
+                         f"{len(axis_names)} axis names")
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def _axis_size(mesh, name: str) -> int:

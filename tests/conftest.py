@@ -7,8 +7,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np
 import pytest
 
-_JAX_CACHE_DIR = os.path.join(os.path.dirname(__file__), ".jax_cache")
-
 
 @pytest.fixture(scope="session", autouse=True)
 def jax_kernel_compilation_cache():
@@ -16,22 +14,16 @@ def jax_kernel_compilation_cache():
 
     The Pallas kernel tests dominate suite wall-time, and most of that is
     XLA re-compiling the same interpreter graphs for every (shape, block,
-    dtype) parametrization on every run. Pointing JAX's persistent
-    compilation cache at a repo-local directory makes every
-    parametrization compile once ever: repeat runs (and other test
-    modules reusing a kernel shape) load the executable from disk.
-    Disable with REPRO_NO_JAX_CACHE=1.
+    dtype) parametrization on every run. JAX's persistent compilation
+    cache (:func:`repro.compile_cache.enable_compile_cache`) makes every
+    parametrization compile once: repeat runs (and other test modules
+    reusing a kernel shape) load the executable from disk.
     """
-    if os.environ.get("REPRO_NO_JAX_CACHE"):
-        yield
-        return
-    try:  # scheduling-core tests are pure NumPy — don't require jax
-        import jax
-    except ImportError:
-        yield
-        return
+    import jax
 
-    jax.config.update("jax_compilation_cache_dir", _JAX_CACHE_DIR)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     # interpret-mode kernels compile on CPU in well under the default
     # 1s/64KB thresholds — cache everything
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -60,6 +60,21 @@ def test_registry_lists_both_backends():
         get_backend("no_such_backend")
 
 
+@pytest.mark.parametrize("name,refused", [("jax", "round24"),
+                                          ("pallas", "uint64")])
+def test_device_backends_refuse_tpu(name, refused, monkeypatch):
+    """On a TPU the jax and pallas backends raise, naming what the v5e
+    compiler refuses, instead of routing to the host or interpreting."""
+    import repro.backend as registry
+    from repro.backend import jax_backend
+    monkeypatch.setattr(jax_backend, "_platform", lambda: "tpu")
+    monkeypatch.setattr(registry, "_SINGLETONS", {})
+    with pytest.raises(RuntimeError, match=refused) as err:
+        get_backend(name)
+    assert "ROADMAP S4" in str(err.value)
+    assert get_backend("numpy").name == "numpy"
+
+
 # ---------------------------------------------------------------------------
 # 1. primitives
 

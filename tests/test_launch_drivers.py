@@ -48,6 +48,27 @@ def test_train_driver_loss_decreases(capsys):
     assert losses[-1] < losses[0] - 1.0, out
 
 
+@pytest.mark.parametrize("env_dir", ["", "/elsewhere/cache"])
+def test_compile_cache_dir(env_dir, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to the one fixed directory at the root of the checkout."""
+    from pathlib import Path
+
+    from repro import compile_cache
+    calls = []
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    compile_cache.enable_compile_cache()
+    if env_dir:
+        assert calls == []
+    else:
+        assert calls == [("jax_compilation_cache_dir",
+                          str(compile_cache.CACHE_DIR))]
+        assert compile_cache.CACHE_DIR.parent == \
+            Path(__file__).resolve().parents[1]
+
+
 def test_inference_demo_driver_runs(capsys):
     from repro.launch.inference_demo import main
     run_cli(main, ["inference_demo", "--arch", "smollm-360m", "--reduced",

@@ -4,7 +4,7 @@ Ground truth is the NumPy counter-hash reference in ``repro.backend.base``
 (the same contract the jit backend is pinned against), so every
 comparison here is ``assert_array_equal`` — no tolerances. The kernels
 mix uint64 and therefore run in **interpreter mode** on CPU CI
-(``ops.piece_window``/``ops.forecast_z`` default to it off-TPU); the
+(``ops.piece_window``/``ops.forecast_z`` default to it off the TPU); the
 ``pallas`` registry backend layers them over the JAX backend, and the
 70k-row case exercises its shape-bucket padding across the 65536
 power-of-two boundary exactly like the acceptance fleet does.
@@ -15,9 +15,7 @@ is installed, with a seeded fallback sweep otherwise.
 import numpy as np
 import pytest
 
-pytest.importorskip("jax")
-
-from jax.experimental import enable_x64
+import jax
 
 from repro.backend import available_backends, get_backend
 from repro.backend.jax_backend import JaxBackend
@@ -47,7 +45,7 @@ def test_piece_window_interpreter_parity(R, S, W, br, bw, rng):
     fold = _U64(rng.integers(0, 2 ** 62))
     amp = np.float32(0.05 * np.sqrt(12.0))
     want = ref.piece_window_ref(levels, slot, fold, rows, 10_000, amp)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(ops.piece_window(
             levels, slot, fold, rows, np.int64(10_000), amp,
             block_r=br, block_w=bw))
@@ -62,7 +60,7 @@ def test_forecast_z_interpreter_parity(R, W, br, bw, rng):
     std = (0.05 + 0.2 * np.minimum(np.arange(1, W + 1) / 1440.0, 1.0)
            ).astype(np.float32)
     want = ref.forecast_z_ref(fold, rows, 777, std)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(ops.forecast_z(fold, rows, _U64(777), std,
                                         block_r=br, block_w=bw))
     np.testing.assert_array_equal(want, got)
@@ -110,7 +108,7 @@ def _key_sweep_case(seed, row_key, segment):
     fold = NP.hash64(seed, 17, np.uint64(segment))
     amp = np.float32(0.1732)
     want = ref.piece_window_ref(levels, slot, fold, rows, segment, amp)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(ops.piece_window(
             levels, slot, _U64(fold), rows, np.int64(segment), amp,
             block_r=16, block_w=16))
@@ -118,7 +116,7 @@ def _key_sweep_case(seed, row_key, segment):
 
     std = np.full(W, 0.07, dtype=np.float32)
     wantz = ref.forecast_z_ref(fold, rows, row_key, std)
-    with enable_x64():
+    with jax.enable_x64(True):
         gotz = np.asarray(ops.forecast_z(_U64(fold), rows, _U64(row_key),
                                          std, block_r=16, block_w=16))
     np.testing.assert_array_equal(wantz, gotz)
