@@ -53,6 +53,7 @@ import numpy as np
 import scipy.sparse as sp
 from scipy.optimize import Bounds, LinearConstraint, milp
 
+from .. import telemetry
 from ..backend import get_backend
 from .types import ClientRegistry, Selection
 
@@ -771,6 +772,7 @@ class _LazyGreedy:
     def probe(self, d: int, feasibility_only: bool = False):
         """Admit up to n clients at duration ``d`` — the lazy equivalent
         of ``_eligible`` + ``_solve_greedy`` over the same inputs."""
+        telemetry.count("solver_probes")
         dd = min(d, self.H)
         if dd <= self._d_infeasible:
             return None
@@ -1150,6 +1152,7 @@ def select_clients(inp: SelectionInputs, n: int, d_max: int,
         model = None
 
     def attempt(d, feasibility_only=False):
+        telemetry.count("solver_probes")
         return find_clients_for_duration(
             inp, d, n, solver, time_limit, cache, model,
             feasibility_only=feasibility_only and solver == "greedy")

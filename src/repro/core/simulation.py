@@ -26,6 +26,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import telemetry
 from repro.data.traces import ScenarioStore
 
 from .power import share_power
@@ -33,6 +34,7 @@ from .strategies import BaseStrategy, EnvView
 from .types import ClientRegistry, RoundResult, Selection
 
 
+@telemetry.spanned("fl.execute_round")
 def execute_round(registry: ClientRegistry, scenario: ScenarioStore,
                   dom_rows: np.ndarray, sel: Selection, now: int,
                   d_max: int, *, constrained: bool = True,
@@ -361,35 +363,50 @@ class FLSimulation:
             round_idx=self.round_idx)
 
     # ------------------------------------------------------------------
+    def _train(self, rr: RoundResult) -> None:
+        """Round ``rr``'s local training, FedAvg, strategy bookkeeping and
+        (every ``eval_every`` rounds) evaluation, in place on ``rr``."""
+        sample_losses: List[np.ndarray] = []
+        if rr.contributors.size:
+            updates = []
+            for pos in rr.contributor_idx:
+                with telemetry.span("fl.local_update"):
+                    upd = self.trainer.local_update(
+                        int(rr.participants[pos]), float(rr.batches[pos]))
+                sample_losses.append(upd["sample_losses"])
+                updates.append(upd)
+            rr.train_loss = float(np.mean(
+                [u["mean_loss"] for u in updates]))
+            if telemetry.enabled:
+                telemetry.count("rows_trained",
+                                sum(float(u["weight"]) for u in updates))
+            with telemetry.span("fl.aggregate"):
+                self.trainer.aggregate(updates)
+            self.participation[rr.contributors] += 1
+        with telemetry.span("fl.record_round"):
+            self.strategy.record_round(rr.contributors, rr.participants,
+                                       sample_losses)
+        if self.eval_every and self.round_idx % self.eval_every == 0:
+            with telemetry.span("fl.evaluate"):
+                rr.eval_metric = float(self.trainer.evaluate())
+
+    # ------------------------------------------------------------------
     def run(self, until_step: Optional[int] = None, max_rounds: Optional[int] = None,
             target_metric: Optional[float] = None, verbose: bool = False):
         until = until_step if until_step is not None else self.scenario.n_steps - 1
         while self.now < until:
             if max_rounds is not None and self.round_idx >= max_rounds:
                 break
-            env = self._env_view()
-            sel = self.strategy.select(env)
-            if sel is None or not len(sel.rows):
-                self.now += self.strategy.wait_for()  # idle fast-forward
-                continue
-            rr = self._execute_round(sel)
-            # local training + aggregation for contributors
-            sample_losses: List[np.ndarray] = []
-            if rr.contributors.size:
-                updates = []
-                for pos in rr.contributor_idx:
-                    upd = self.trainer.local_update(int(rr.participants[pos]),
-                                                    float(rr.batches[pos]))
-                    sample_losses.append(upd["sample_losses"])
-                    updates.append(upd)
-                rr.train_loss = float(np.mean(
-                    [u["mean_loss"] for u in updates]))
-                self.trainer.aggregate(updates)
-                self.participation[rr.contributors] += 1
-            self.strategy.record_round(rr.contributors, rr.participants,
-                                       sample_losses)
-            if self.eval_every and self.round_idx % self.eval_every == 0:
-                rr.eval_metric = float(self.trainer.evaluate())
+            with telemetry.round(self.round_idx):
+                env = self._env_view()
+                with telemetry.span("fl.select"):
+                    sel = self.strategy.select(env)
+                if sel is None or not len(sel.rows):
+                    self.now += self.strategy.wait_for()  # idle fast-forward
+                    continue
+                rr = self._execute_round(sel)
+                self._train(rr)
+                telemetry.count("rounds")
             self.results.append(rr)
             self.round_idx += 1
             self.now += max(rr.duration, 1)

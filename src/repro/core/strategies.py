@@ -24,6 +24,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from .. import telemetry
 from .fairness import Blocklist
 from .selection import LazySelectionInputs, SelectionInputs, select_clients
 from .types import ClientRegistry, Selection
@@ -319,9 +320,11 @@ class FedZeroStrategy(BaseStrategy):
         cand = np.nonzero((sigma > 0) & dom_ok[env.dom_rows])[0]
         sel = None
         if cand.size >= self.n:
-            inp = self._selection_inputs(env, cand, sigma, excess_fc)
-            sel = select_clients(inp, self.n, self.d_max, solver=self.solver,
-                                 search=self.search)
+            with telemetry.span("fl.select.inputs"):
+                inp = self._selection_inputs(env, cand, sigma, excess_fc)
+            with telemetry.span("fl.select.solve"):
+                sel = select_clients(inp, self.n, self.d_max,
+                                     solver=self.solver, search=self.search)
         if sel is not None:
             self._rounds_since_grid += 1
             return sel

@@ -23,6 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.data.federated import FederatedData
 from repro.optim import fedprox_loss, sgd
 
@@ -53,23 +54,26 @@ class JaxTrainer:
 
         @jax.jit
         def local_step(params, opt_state, batch, global_params):
-            loss, grads = jax.value_and_grad(self._local_loss)(
-                params, batch, global_params)
-            params, opt_state = self.opt.update(grads, opt_state, params)
+            with jax.named_scope("fl.local_step"):
+                loss, grads = jax.value_and_grad(self._local_loss)(
+                    params, batch, global_params)
+                params, opt_state = self.opt.update(grads, opt_state,
+                                                    params)
             return params, opt_state, loss
 
         self._local_step = local_step
 
         @jax.jit
         def sample_losses_fn(params, batch):
-            logits = model.logits_fn(params, batch)
-            logits = logits.astype(jnp.float32)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(
-                logits, batch["labels"][..., None], axis=-1)[..., 0]
-            nll = logz - gold
-            if nll.ndim > 1:  # LM: mean over sequence
-                nll = nll.mean(axis=tuple(range(1, nll.ndim)))
+            with jax.named_scope("fl.sample_losses"):
+                logits = model.logits_fn(params, batch)
+                logits = logits.astype(jnp.float32)
+                logz = jax.nn.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(
+                    logits, batch["labels"][..., None], axis=-1)[..., 0]
+                nll = logz - gold
+                if nll.ndim > 1:  # LM: mean over sequence
+                    nll = nll.mean(axis=tuple(range(1, nll.ndim)))
             return nll
 
         self._sample_losses = sample_losses_fn
@@ -81,14 +85,19 @@ class JaxTrainer:
         opt_state = self.opt.init(params)
         losses = []
         for _ in range(steps):
-            batch = self.data.sample_batch(client, self.batch_size, self.rng)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            params, opt_state, loss = self._local_step(
-                params, opt_state, batch, self.params)
-            losses.append(float(loss))
-        probe = self.data.sample_batch(client, 4 * self.batch_size, self.rng)
-        probe = {k: jnp.asarray(v) for k, v in probe.items()}
-        sample_losses = np.asarray(self._sample_losses(params, probe))
+            with telemetry.span("fl.local_update.batch"):
+                batch = telemetry.to_device(self.data.sample_batch(
+                    client, self.batch_size, self.rng))
+            with telemetry.span("fl.local_update.step"):
+                params, opt_state, loss = self._local_step(
+                    params, opt_state, batch, self.params)
+            losses.append(float(telemetry.to_host(loss)))
+        telemetry.count("local_steps", steps)
+        with telemetry.span("fl.local_update.probe"):
+            probe = telemetry.to_device(self.data.sample_batch(
+                client, 4 * self.batch_size, self.rng))
+            sample_losses = telemetry.to_host(
+                self._sample_losses(params, probe))
         return {"row": row, "params": params,
                 "weight": float(steps * self.batch_size),
                 "sample_losses": sample_losses,
@@ -109,10 +118,12 @@ class JaxTrainer:
         td = self.data.test_data
         n = len(next(iter(td.values())))
         take = min(self.eval_batch, n)
-        batch = {k: jnp.asarray(v[:take]) for k, v in td.items()}
-        logits = self.model.logits_fn(self.params, batch)
-        pred = jnp.argmax(logits, axis=-1)
-        return float(jnp.mean((pred == batch["labels"]).astype(jnp.float32)))
+        batch = telemetry.to_device({k: v[:take] for k, v in td.items()})
+        with jax.named_scope("fl.evaluate"):
+            logits = self.model.logits_fn(self.params, batch)
+            pred = jnp.argmax(logits, axis=-1)
+            acc = jnp.mean((pred == batch["labels"]).astype(jnp.float32))
+        return float(telemetry.to_host(acc))
 
 
 class ProxyTrainer:
