@@ -1,8 +1,9 @@
 """Trainers plugged into the FL simulation.
 
 * ``JaxTrainer``   — real federated training in JAX: per-client FedProx/SGD
-  local updates on the client's data shard, FedAvg aggregation weighted by
-  samples processed, evaluation on a held-out test set.
+  local updates on the client's data shard, each one device program (its
+  batches staged once, its losses read back once), FedAvg aggregation
+  weighted by samples processed, evaluation on a held-out test set.
 * ``ProxyTrainer`` — analytic convergence proxy for scheduler-scale
   experiments (100k clients, 7 simulated days) where real training is not
   the object of study. Calibrated to show diminishing returns per client
@@ -22,6 +23,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro import telemetry
 from repro.data.federated import FederatedData
@@ -53,55 +55,75 @@ class JaxTrainer:
             self._local_loss = lambda p, b, g: model.loss(p, b)
 
         @jax.jit
-        def local_step(params, opt_state, batch, global_params):
-            with jax.named_scope("fl.local_step"):
-                loss, grads = jax.value_and_grad(self._local_loss)(
-                    params, batch, global_params)
-                params, opt_state = self.opt.update(grads, opt_state,
-                                                    params)
-            return params, opt_state, loss
+        def local_update(global_params, batches, steps, probe):
+            """``steps`` SGD steps from the global model, batch ``i`` of
+            the ``[max_steps, B, ...]`` stack at step ``i``, then the
+            probe's per-sample NLL on the final parameters. Returns the
+            parameters, the ``[max_steps]`` float32 losses (zero past
+            ``steps``) and the probe's losses."""
+            def step(i, carry):
+                params, opt_state, losses = carry
+                batch = jax.tree.map(
+                    lambda x: lax.dynamic_index_in_dim(x, i, keepdims=False),
+                    batches)
+                with jax.named_scope("fl.local_step"):
+                    loss, grads = jax.value_and_grad(self._local_loss)(
+                        params, batch, global_params)
+                    params, opt_state = self.opt.update(grads, opt_state,
+                                                        params)
+                return (params, opt_state,
+                        losses.at[i].set(loss.astype(jnp.float32)))
 
-        self._local_step = local_step
-
-        @jax.jit
-        def sample_losses_fn(params, batch):
+            n = jax.tree.leaves(batches)[0].shape[0]
+            params, _, losses = lax.fori_loop(
+                0, steps, step, (global_params, self.opt.init(global_params),
+                                 jnp.zeros(n, jnp.float32)))
             with jax.named_scope("fl.sample_losses"):
-                logits = model.logits_fn(params, batch)
-                logits = logits.astype(jnp.float32)
+                logits = model.logits_fn(params, probe).astype(jnp.float32)
                 logz = jax.nn.logsumexp(logits, axis=-1)
                 gold = jnp.take_along_axis(
-                    logits, batch["labels"][..., None], axis=-1)[..., 0]
+                    logits, probe["labels"][..., None], axis=-1)[..., 0]
                 nll = logz - gold
                 if nll.ndim > 1:  # LM: mean over sequence
                     nll = nll.mean(axis=tuple(range(1, nll.ndim)))
-            return nll
+            return params, losses, nll
 
-        self._sample_losses = sample_losses_fn
+        self._local_update = local_update
+
+    def _stage(self, draws: List[Dict]) -> Dict:
+        """The step batches as one host array per key, ``[max_steps, B,
+        ...]``, zero past the last step: one shape per configuration."""
+        out = {}
+        for k, v in draws[0].items():
+            buf = np.zeros((self.max_steps,) + v.shape, v.dtype)
+            np.stack([d[k] for d in draws], out=buf[:len(draws)])
+            out[k] = buf
+        return out
 
     def local_update(self, row: int, n_batches: float) -> Dict:
+        """One client's FedProx update as one device program: its batches
+        are drawn and staged at once, and its losses read back at once."""
         client = self._names[row]
         steps = int(min(max(1, round(n_batches)), self.max_steps))
-        params = self.params
-        opt_state = self.opt.init(params)
-        losses = []
-        for _ in range(steps):
-            with telemetry.span("fl.local_update.batch"):
-                batch = telemetry.to_device(self.data.sample_batch(
-                    client, self.batch_size, self.rng))
-            with telemetry.span("fl.local_update.step"):
-                params, opt_state, loss = self._local_step(
-                    params, opt_state, batch, self.params)
-            losses.append(float(telemetry.to_host(loss)))
+        with telemetry.span("fl.local_update.batch"):
+            draws = [self.data.sample_batch(client, self.batch_size,
+                                            self.rng) for _ in range(steps)]
+            probe = self.data.sample_batch(client, 4 * self.batch_size,
+                                           self.rng)
+            staged = telemetry.to_device({
+                "batches": self._stage(draws), "steps": np.int32(steps),
+                "probe": probe})
+        with telemetry.span("fl.local_update.step"):
+            params, losses, sample_losses = self._local_update(
+                self.params, **staged)
         telemetry.count("local_steps", steps)
-        with telemetry.span("fl.local_update.probe"):
-            probe = telemetry.to_device(self.data.sample_batch(
-                client, 4 * self.batch_size, self.rng))
-            sample_losses = telemetry.to_host(
-                self._sample_losses(params, probe))
+        telemetry.count("fused_updates")
+        telemetry.count("pad_steps", self.max_steps - steps)
+        losses, sample_losses = telemetry.to_host((losses, sample_losses))
         return {"row": row, "params": params,
                 "weight": float(steps * self.batch_size),
                 "sample_losses": sample_losses,
-                "mean_loss": float(np.mean(losses))}
+                "mean_loss": float(losses[:steps].astype(np.float64).mean())}
 
     def aggregate(self, updates: List[Dict]):
         weights = np.array([u["weight"] for u in updates], np.float32)

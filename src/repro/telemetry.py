@@ -37,7 +37,6 @@ from typing import Dict
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 enabled = False
 
@@ -139,26 +138,29 @@ def count(name: str, n: float = 1) -> None:
             _counters[name] += n
 
 
-def to_host(x) -> np.ndarray:
-    """``np.asarray(x)``: a readback, in the span ``fl.sync`` and counted
-    as ``host_syncs`` while the recorder is on."""
+def to_host(x):
+    """``jax.device_get(x)``: the NumPy arrays of ``x`` (an array or a
+    pytree of them), fetched together as one readback, in the span
+    ``fl.sync`` and counted once as ``host_syncs`` while the recorder is
+    on."""
     if not enabled:
-        return np.asarray(x)
+        return jax.device_get(x)
     with span("fl.sync"):
-        out = np.asarray(x)
+        out = jax.device_get(x)
     count("host_syncs")
     return out
 
 
-def to_device(batch: dict) -> dict:
-    """``jnp.asarray`` of each array of a batch; their ``nbytes`` are
-    counted as ``h2d_bytes`` while the recorder is on (arrays already on
-    the device count nothing)."""
+def to_device(batch):
+    """A pytree of host arrays put on the default device in one
+    ``jax.device_put``; their ``nbytes`` are counted as ``h2d_bytes``
+    while the recorder is on (arrays already on the device count
+    nothing)."""
     if enabled:
         count("h2d_bytes", sum(int(np.asarray(v).nbytes)
-                               for v in batch.values()
+                               for v in jax.tree.leaves(batch)
                                if not isinstance(v, jax.Array)))
-    return {k: jnp.asarray(v) for k, v in batch.items()}
+    return jax.device_put(batch)
 
 
 def _innermost() -> str:

@@ -182,14 +182,19 @@ def test_a_local_update_counts_its_syncs_steps_and_bytes(steps):
     trainer.local_update(1, float(steps))
     snap = telemetry.snapshot()
     counters, spans = snap["counters"], snap["spans"]
-    assert counters["host_syncs"] == steps + 1   # each step's loss, a probe
+    # one update, one device program: the step batches staged once,
+    # padded to max_steps, with the probe and the step count (an int32)
+    assert counters["host_syncs"] == 1
     assert counters["local_steps"] == steps
+    assert counters["fused_updates"] == 1
+    assert counters["pad_steps"] == trainer.max_steps - steps
     assert len(sizes) == steps + 1
-    assert counters["h2d_bytes"] == sum(sizes)
-    assert spans["fl.sync"]["calls"] == steps + 1
-    assert spans["fl.local_update.batch"]["calls"] == steps
-    assert spans["fl.local_update.step"]["calls"] == steps
-    assert spans["fl.local_update.probe"]["calls"] == 1
+    assert counters["h2d_bytes"] == (trainer.max_steps * sizes[0]
+                                     + sizes[-1] + 4)
+    assert spans["fl.sync"]["calls"] == 1
+    assert spans["fl.local_update.batch"]["calls"] == 1
+    assert spans["fl.local_update.step"]["calls"] == 1
+    assert "fl.local_update.probe" not in spans
 
 
 def test_a_compile_is_counted_under_the_span_open_then():
@@ -268,7 +273,6 @@ def test_a_profiler_trace_holds_the_spans_and_the_round_markers(tmp_path):
                 if e.name == "fl.round":
                     steps += [v for k, v in e.stats if k == "step_num"]
     assert {"fl.round", "fl.select", "fl.execute_round", "fl.local_update",
-            "fl.local_update.batch", "fl.local_update.step",
-            "fl.local_update.probe", "fl.sync", "fl.aggregate",
-            "fl.record_round", "fl.evaluate"} <= names
+            "fl.local_update.batch", "fl.local_update.step", "fl.sync",
+            "fl.aggregate", "fl.record_round", "fl.evaluate"} <= names
     assert sorted(steps) == [1, 2]

@@ -1,4 +1,4 @@
-"""Compile the main path's kernels and the KWT-1 local step for a v5e.
+"""Compile the main path's kernels and the KWT-1 local update for a v5e.
 
 The TPU compiler compiles for a described, unattached ``v5e:2x2``
 topology, so these tests catch what interpret mode cannot (a lowering the
@@ -81,8 +81,10 @@ def test_rwkv_scan_compiles(one_chip):
 
 
 def test_kwt1_local_step_compiles(one_chip):
-    """The FedProx local step of a KWT-1 JaxTrainer at its published
-    width: d 64, 12 layers, 1 head, MLP 256, 98 MFCC patches, 35 classes."""
+    """The FedProx local update of a KWT-1 JaxTrainer at its published
+    width (d 64, 12 layers, 1 head, MLP 256, 98 MFCC patches, 35 classes),
+    as the one program the chip runs per update: the loop of local steps
+    over the staged ``[max_steps, B, ...]`` batches, then the probe."""
     from repro.core import JaxTrainer
     from repro.data.federated import synthetic_speech
     from repro.models import KWTModel
@@ -93,10 +95,13 @@ def test_kwt1_local_step_compiles(one_chip):
     tr = JaxTrainer(KWTModel(n_classes=35, n_patches=98), data)
     on_chip = lambda t: jax.tree.map(
         lambda a: _spec(one_chip, a.shape, a.dtype), t)
-    params = on_chip(tr.params)
-    opt_state = on_chip(tr.opt.init(tr.params))
-    batch = on_chip(data.sample_batch("c0", tr.batch_size,
-                                      np.random.default_rng(0)))
-    compiled = tr._local_step.lower(params, opt_state, batch,
-                                    params).compile()
+    rng = np.random.default_rng(0)
+    draws = [data.sample_batch("c0", tr.batch_size, rng) for _ in range(3)]
+    probe = data.sample_batch("c0", 4 * tr.batch_size, rng)
+    batches = tr._stage(draws)
+    assert batches["mfcc"].shape == (tr.max_steps, tr.batch_size, 98, 40)
+    compiled = tr._local_update.lower(
+        on_chip(tr.params), on_chip(batches),
+        _spec(one_chip, (), jnp.int32), on_chip(probe)).compile()
     assert compiled.memory_analysis() is not None
+    assert "while" in compiled.as_text()
