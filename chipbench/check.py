@@ -37,13 +37,20 @@ compared, each with its limit:
   of a sample loss of the update's probe rows;
 - ``aggregate_ulps``: the largest gap of an element of the program's
   FedAvg from the reference's float32 FedAvg, in units of the weights'
-  dtype spacing there (:func:`_ulps`);
+  dtype spacing there (:func:`_ulps`); an element that is exactly 0 on
+  every side reads 0, and the leaf that holds the largest gap is named on
+  standard error;
 - ``eval_gap``: the program's evaluation against the reference's accuracy
   of the reference FedAvg, stored in the weights' dtype, on the same test
   rows;
 - ``foreign_rows``: rows handed to an update that are not rows of its
   client's shard as the benchmark generated it, or calls of the data that
   do not match the update's steps (exact).
+
+No number reads NaN: a gap of two values that agree exactly reads 0, also
+over a floor of 0, and a gap where the program or the reference made a
+value non-finite, or where two values differ over a floor of 0, reads
+``inf``.
 """
 from __future__ import annotations
 
@@ -89,16 +96,40 @@ def _ulps(got, want, updates):
     ``want``, in units of ``got``'s dtype spacing at the larger of
     ``want`` and the largest update there: an aggregate stored in that
     dtype from the same float32 sum is within half a unit, one summed in
-    another order within a few."""
+    another order within a few. Returns the gap and the index, among the
+    leaves of ``got``, of the leaf that holds it.
+
+    The unit is never under float32's smallest normal number: XLA flushes
+    subnormals to 0, and an element that is 0 on every side would read
+    0/0. So such an element reads 0, and the unit is as the dtype's
+    spacing gives it wherever the scale is 2^-103 (float32) or 2^-119
+    (bfloat16) or more. A NaN or inf in ``got``, ``want`` or an update
+    reads ``inf``."""
     def leaf(g, w, *us):
         eps = jnp.finfo(g.dtype).eps
         scale = reduce(jnp.maximum, [jnp.abs(w)] + [
             jnp.abs(u.astype(F32)) for u in us])
         tiny = jnp.finfo(g.dtype).tiny
-        unit = eps * jnp.exp2(jnp.floor(jnp.log2(jnp.maximum(scale, tiny))))
-        return jnp.max(jnp.abs(g.astype(F32) - w) / unit)
-    return jnp.max(jnp.stack(jax.tree.leaves(
-        jax.tree.map(leaf, got, want, *updates))))
+        unit = jnp.maximum(
+            eps * jnp.exp2(jnp.floor(jnp.log2(jnp.maximum(scale, tiny)))),
+            jnp.finfo(F32).tiny)
+        gap = jnp.abs(g.astype(F32) - w) / unit
+        return jnp.max(jnp.where(jnp.isnan(gap), jnp.inf, gap))
+    gaps = jnp.stack(jax.tree.leaves(jax.tree.map(leaf, got, want, *updates)))
+    return jnp.max(gaps), jnp.argmax(gaps)
+
+
+def name_leaf(got, want, updates, i: int) -> str:
+    """The key path of ``got``'s leaf ``i``, and which of the program's
+    aggregate, the reference's and the updates hold a non-finite value
+    there."""
+    path, _ = jax.tree_util.tree_flatten_with_path(got)
+    sides = [("program", got), ("reference", want)] + [
+        (f"update {k}", u) for k, u in enumerate(updates)]
+    bad = [name for name, tree in sides
+           if not bool(jnp.all(jnp.isfinite(jax.tree.leaves(tree)[i])))]
+    return (jax.tree_util.keystr(path[i][0]) + ": "
+            + (f"non-finite in {', '.join(bad)}" if bad else "all finite"))
 
 
 def steps_of(n_batches: float, max_steps: int) -> int:
@@ -258,16 +289,40 @@ def _device(batch):
 
 
 def rel(a: float, b: float) -> float:
+    """The gap of ``a`` from ``b``, relative where ``b`` is not 0; ``inf``
+    where either is not finite."""
+    if not (math.isfinite(a) and math.isfinite(b)):
+        return math.inf
     return abs(a - b) / abs(b) if b else abs(a - b)
+
+
+def gaps_over(got: np.ndarray, want: np.ndarray, floor) -> np.ndarray:
+    """``|got - want| / floor`` elementwise: 0 where the two agree exactly,
+    ``inf`` where they differ over a floor of 0 or either is not finite."""
+    diff = np.abs(got - want)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        gap = np.where(diff == 0, 0.0, diff / floor)
+    sound = np.isfinite(got) & np.isfinite(want) & ~np.isnan(gap)
+    return np.where(sound, gap, np.inf)
 
 
 def leaf_gaps(got: np.ndarray, want: np.ndarray, keep=None) -> np.ndarray:
     """Per leaf, the gap of the norms over the reference's norm of that leaf
-    or of the median (kept) leaf, whichever is larger; 0 where not kept."""
+    or of the median (kept) leaf, whichever is larger; 0 where not kept,
+    unless a norm there is not finite."""
     if keep is None:
         keep = np.ones(len(want), bool)
     floor = np.maximum(want, np.median(want[keep]))
-    return np.where(keep, np.abs(got - want) / floor, 0.0)
+    finite = np.isfinite(got) & np.isfinite(want)
+    return np.where(keep | ~finite, gaps_over(got, want, floor), 0.0)
+
+
+def sample_loss_gap(got: np.ndarray, want: np.ndarray) -> float:
+    """The worst relative gap of a probe row's loss; ``inf`` where the
+    shapes differ."""
+    if got.shape != want.shape:
+        return math.inf
+    return float(np.max(gaps_over(got, want, np.abs(want))))
 
 
 def kept(ref_grad: np.ndarray) -> np.ndarray:
@@ -345,7 +400,11 @@ def round_numbers(last: LastRound, cfg: dict, ref: Reference,
     weights = np.array([u.weight for u in updates], np.float32)
     params = [u.params for u in updates]
     avg = _fedavg(params, list(weights / weights.sum()))
-    aggregate_ulps = float(_ulps(last.agg, avg, params))
+    ulps, at = _ulps(last.agg, avg, params)
+    aggregate_ulps = float(ulps)
+    from chipbench.bench import log  # bench imports this module
+    log(f"aggregate_ulps {aggregate_ulps!r} at leaf "
+        f"{name_leaf(last.agg, avg, params, int(at))}")
     avg = jax.tree.map(lambda a, w: a.astype(w.dtype), avg, w_prev)
     del params
     n_eval = min(cfg["train"]["eval_batch"], len(test["labels"]))
@@ -386,9 +445,7 @@ def round_numbers(last: LastRound, cfg: dict, ref: Reference,
         gaps["round_change_gap"].append(
             float(np.max(leaf_gaps(change, want_change, keep))))
         gaps["sample_loss_gap"].append(
-            float(np.max(np.abs(samples - want_samples)
-                         / np.abs(want_samples)))
-            if samples.shape == want_samples.shape else math.inf)
+            sample_loss_gap(samples, want_samples))
     return {**numbers, **{k: max(v) for k, v in gaps.items()}}
 
 

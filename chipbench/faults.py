@@ -7,6 +7,7 @@ nothing between chips, so that fault has no place here."""
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 
 def unchanged(sim):
@@ -65,6 +66,23 @@ def half_contributors(sim):
     tr.aggregate = lambda updates: inner(updates[:max(1, len(updates) // 2)])
 
 
+def nonfinite_aggregate(sim):
+    """An aggregate that writes a NaN into the first element of its largest
+    leaf."""
+    tr = sim.trainer
+    inner = tr.aggregate
+
+    def aggregate(updates):
+        inner(updates)
+        leaves, treedef = jax.tree.flatten(tr.params)
+        i = max(range(len(leaves)), key=lambda j: leaves[j].size)
+        leaves[i] = leaves[i].ravel().at[0].set(jnp.nan).reshape(
+            leaves[i].shape)
+        tr.params = jax.tree.unflatten(treedef, leaves)
+
+    tr.aggregate = aggregate
+
+
 def eval_labels_off(sim):
     """An evaluation that scores each prediction against the next label."""
     tr = sim.trainer
@@ -86,4 +104,5 @@ FAULTS = {"unchanged": unchanged, "half_batch": half_batch,
           "altered_update": altered_update,
           "stale_aggregate": stale_aggregate,
           "half_contributors": half_contributors,
+          "nonfinite_aggregate": nonfinite_aggregate,
           "eval_labels_off": eval_labels_off}

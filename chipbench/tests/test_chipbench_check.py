@@ -11,10 +11,13 @@ catch, on the CPU, through the harness's own run of a small cell:
 - a run with each fault of ``faults.py`` planted underneath the check's
   capture comes out not correct: a local update that returns the global
   model, half of each batch left out, an update altered where it is
-  produced, an aggregate that leaves the global model as it was or that
-  averages half the contributors, and an evaluation scored against the
-  wrong labels.
+  produced, an aggregate that leaves the global model as it was, that
+  averages half the contributors or that writes a NaN into it, and an
+  evaluation scored against the wrong labels. No number reads NaN: the
+  aggregate with a NaN reads ``inf`` ulps.
 """
+import math
+
 import pytest
 
 from chipbench.faults import FAULTS
@@ -45,3 +48,6 @@ def test_the_control_is_not_correct(name, at_published_widths):
 def test_a_planted_fault_is_not_correct(name, fault):
     out = run_small(name, seed=11, fault=FAULTS[fault])
     assert out["correct"] is False, out["check"]
+    assert not any(math.isnan(c["value"]) for c in out["check"].values())
+    if fault == "nonfinite_aggregate":
+        assert out["check"]["aggregate_ulps"]["value"] == math.inf
